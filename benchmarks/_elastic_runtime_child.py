@@ -14,6 +14,8 @@ import json
 import os
 import time
 
+# host devices only: this process must never take the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", "")

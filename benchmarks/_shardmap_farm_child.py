@@ -3,6 +3,8 @@ placeholder host devices.  Prints CSV rows on stdout."""
 
 import os
 
+# host devices only: this process must never take the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=16 "
     + os.environ.get("XLA_FLAGS", "")

@@ -158,8 +158,10 @@ def _real_spmd_rows():
         timeout=900,
     )
     if proc.returncode != 0:
-        return [Row("elastic_runtime/spmd/FAILED", 0.0,
-                    proc.stderr.strip()[-200:])], []
+        raise RuntimeError(
+            f"elastic_runtime child exited {proc.returncode}: "
+            f"{proc.stderr.strip()[-2000:]}"
+        )
     rows, records = [], []
     for line in proc.stdout.strip().splitlines():
         if line.startswith("{"):

@@ -18,6 +18,9 @@ from benchmarks.common import emit
 
 
 def main() -> None:
+    from repro import compile_cache
+
+    compile_cache.enable()
     modules = [
         "benchmarks.accumulator_scaling",
         "benchmarks.accumulator_frequency",

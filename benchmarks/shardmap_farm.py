@@ -32,7 +32,10 @@ def run() -> list[Row]:
         timeout=900,
     )
     if proc.returncode != 0:
-        return [Row("shardmap_farm/FAILED", 0.0, proc.stderr.strip()[-200:])]
+        raise RuntimeError(
+            f"shardmap_farm child exited {proc.returncode}: "
+            f"{proc.stderr.strip()[-2000:]}"
+        )
     rows = []
     for line in proc.stdout.strip().splitlines():
         parts = line.split(",", 2)

@@ -9,11 +9,14 @@
 * checkpoint-mediated mesh resize for a training state.
 
 Run:  PYTHONPATH=src python examples/elastic_farm.py
-(8 placeholder host devices are set before jax import — demo only.)
+(8 placeholder host devices on the CPU backend are set before jax import —
+demo only; it never takes an accelerator.)
 """
 
 import os
 
+# host devices only: this demo must never take the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
 )

@@ -14,7 +14,3 @@ Subsystem map (see README.md for the full tour):
   applications: continuous-batching serving (S2 session store),
   fault-tolerant training (S3/S4/S5), multi-pod launch tooling.
 """
-
-from repro import compat as _compat
-
-_compat.install()

@@ -33,10 +33,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import semantics
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
+shard_map = jax.shard_map
 
 
 def _axis_size(mesh: Mesh, axis: str) -> int:
@@ -44,9 +41,9 @@ def _axis_size(mesh: Mesh, axis: str) -> int:
 
 
 def _pvary(x, axis: str):
-    """Mark a replicated value as device-varying over ``axis`` (JAX >= 0.6 VMA
-    typing) so it can seed a scan carry that becomes varying."""
-    return jax.tree.map(lambda leaf: lax.pvary(leaf, (axis,)), x)
+    """Mark a replicated value as device-varying over ``axis`` (VMA typing)
+    so it can seed a scan carry that becomes varying."""
+    return jax.tree.map(lambda leaf: lax.pcast(leaf, (axis,), to="varying"), x)
 
 
 def _unvary(x, axis: str):

@@ -67,6 +67,7 @@ from __future__ import annotations
 
 import atexit
 import collections
+import contextlib
 import dataclasses
 import multiprocessing
 import multiprocessing.connection
@@ -92,6 +93,22 @@ from repro.runtime.supervisor import WorkerFailure
 
 _FIRE_KEYS = ("key", "start", "end", "value", "count")
 _LATE_KEYS = ("key", "value", "ts", "start", "pos")
+
+
+@contextlib.contextmanager
+def _host_only_env():
+    """Hide the accelerator from processes spawned inside the block: the
+    spawned interpreter inherits ``JAX_PLATFORMS=cpu`` before it imports
+    JAX.  The parent's own JAX is already initialized and unaffected."""
+    saved = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if saved is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = saved
 
 
 def _owned(d: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
@@ -189,16 +206,17 @@ class DistributedKeyedPlane(KeyedWindowAdapter):
     onto each worker process.  ``spares`` keeps that many warm spare hosts
     on standby: a worker death promotes a spare into the hole instantly,
     so failover re-attach never pays process startup.  ``prespawn``
-    pre-starts enough hosts for that many shards at the first attach;
-    ``start_method`` picks the multiprocessing context (default ``spawn``
-    — safe after the parent initialized JAX; ``fork`` starts faster).
+    pre-starts enough hosts for that many shards at the first attach.
+
+    Shard hosts are host processes: each is spawned with
+    ``JAX_PLATFORMS=cpu`` in its environment, so it never takes the
+    accelerator, which belongs to the coordinator process alone.
     """
 
     def __init__(self, spec: WindowSpec, *, num_slots: int,
                  impl: str = "segment", backend: str = "host",
                  capacity: int = 1024, ttl: int | None = None,
                  max_probes: int = 16, prespawn: Optional[int] = None,
-                 start_method: str = "spawn",
                  blackbox_dir: Optional[str] = None,
                  transport: Optional[str] = None,
                  shards_per_host: int = 1,
@@ -215,7 +233,6 @@ class DistributedKeyedPlane(KeyedWindowAdapter):
             live=True, fused=False,
         )
         self.prespawn = prespawn
-        self.start_method = start_method
         self.blackbox_dir = blackbox_dir or os.path.join(
             tempfile.gettempdir(), f"repro-dist-{os.getpid()}"
         )
@@ -227,7 +244,7 @@ class DistributedKeyedPlane(KeyedWindowAdapter):
         self.shards_per_host = max(1, int(shards_per_host))
         self.spares = max(0, int(spares))
         self.shm_capacity = int(shm_capacity)
-        self._ctx = multiprocessing.get_context(start_method)
+        self._ctx = multiprocessing.get_context("spawn")
         self._pool: List[Optional[_HostHandle]] = []
         self._spares: List[_HostHandle] = []
         self._spawned = 0                     # spawn ordinal counter
@@ -325,7 +342,8 @@ class DistributedKeyedPlane(KeyedWindowAdapter):
             target=shardhost.serve, args=(child, cfg), daemon=True,
             name=f"shardhost-{ident}",
         )
-        proc.start()
+        with _host_only_env():
+            proc.start()
         child.close()  # parent keeps one end only, so EOF means death
         return _HostHandle(ident, proc, ShmTransport(parent),
                            cfg["blackbox_path"], rings)

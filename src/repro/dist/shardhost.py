@@ -30,10 +30,10 @@ Chrome-trace black box before dying on any error (including the CRASH
 failure-drill frame) — the coordinator collects the dump file when it sees
 the pipe close.
 
-Workers are spawn-safe: :func:`serve` is a plain module-level entry point
+Workers are spawned: :func:`serve` is a plain module-level entry point
 taking only picklable arguments, and engine construction happens inside the
-child, so ``start_method="spawn"`` (the default — safe after the parent has
-initialized JAX threads) and ``"fork"`` both work.
+child.  The coordinator spawns each host with ``JAX_PLATFORMS=cpu``, so a
+host never takes the accelerator — the coordinator process holds it.
 """
 
 from __future__ import annotations

@@ -73,7 +73,7 @@ def decode_attention(
     softcap: float = 0.0,
     window: int = 0,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """q [B, Hq, hd]; cache_k/v [B, Hkv, S, hd]; valid_len scalar int32.
 

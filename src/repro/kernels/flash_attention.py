@@ -104,7 +104,7 @@ def flash_attention(
     softcap: float = 0.0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """q [B, Hq, Sq, hd]; k, v [B, Hkv, Skv, hd] -> [B, Hq, Sq, hd]."""
     B, Hq, Sq, hd = q.shape

@@ -22,9 +22,9 @@ JAX x64-off config ``jnp`` would silently narrow anyway; the dispatch layer
 (:func:`repro.kernels.ops.table_lookup`) does the split host-side with
 uint64 wraparound so negative keys round-trip exactly.
 
-The accumulate half of the table update is the ``scatter_add`` kernel from
-``segment_reduce.py`` (shipped with PR 2 precisely for this table); this
-module only adds the match/lookup kernel and its jnp reference.
+The accumulate half of the table update runs on the host
+(:meth:`repro.keyed.table.BatchedWindowTable.update`); this module only
+holds the match/lookup kernels.
 """
 
 from __future__ import annotations
@@ -78,7 +78,7 @@ def _table_lookup_kernel(
 
 def table_lookup(
     cell_lo_hi, table_lo_hi, occ, *, block_cells: int = 128,
-    block_table: int = 512, interpret: bool = True,
+    block_table: int = 512, interpret: bool = False,
 ):
     """Row index of each cell in the table, ``capacity`` = miss.
 
@@ -168,7 +168,7 @@ def _batched_table_lookup_kernel(
 
 def batched_table_lookup(
     cell_planes, table_planes, occ, *, block_cells: int = 128,
-    block_table: int = 512, interpret: bool = True,
+    block_table: int = 512, interpret: bool = False,
 ):
     """Global row of each cell in an ``n_w``-shard batched table (stacked
     shard-major to ``[n_w * capacity]`` planes); ``n_w * capacity`` = miss.

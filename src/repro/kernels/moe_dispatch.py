@@ -33,7 +33,7 @@ def _kernel(row_token_ref, x_ref, o_ref, *, rows_per_block: int, num_tokens: int
     o_ref[0] = jnp.where(valid, row, jnp.zeros_like(row))
 
 
-def moe_gather(x, row_token, *, interpret: bool = True):
+def moe_gather(x, row_token, *, interpret: bool = False):
     """x [T, d]; row_token [R] int32 in [0, T] (T = dummy).  Returns [R, d].
 
     Equivalent to `ref.moe_gather_ref` (x padded with a zero row)."""

@@ -1,8 +1,10 @@
 """Jit'd dispatch layer: Pallas kernel on TPU, pure-jnp reference elsewhere.
 
-`use_kernels(True/False/"auto")` flips the implementation globally; "auto"
-selects kernels when the default backend is TPU.  The model code calls these
-wrappers, so swapping implementations never touches model definitions.
+`use_kernels("auto" | "kernel" | "ref" | "interpret")` flips the
+implementation globally; "auto" selects the compiled kernels when the default
+backend is TPU and the reference otherwise, and "interpret" runs the kernels
+in Pallas interpret mode (CPU tests).  The model code calls these wrappers,
+so swapping implementations never touches model definitions.
 """
 
 from __future__ import annotations
@@ -41,6 +43,12 @@ def _kernel_enabled() -> Optional[bool]:
     if _MODE == "interpret":
         return None
     return True if jax.default_backend() == "tpu" else False
+
+
+def compiled_kernels() -> bool:
+    """True when the Pallas kernels run compiled: neither the jnp reference
+    nor interpret mode — what a run on the chip must see."""
+    return _kernel_enabled() is True
 
 
 def kernels_active() -> bool:
@@ -96,21 +104,6 @@ def segment_sum(values, seg_ids, num_segments: int):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("num_segments",))
-def segment_sum_sorted(values, seg_ids, num_segments: int):
-    """Fast path for ``seg_ids`` already sorted ascending (the keyed
-    algorithm layer sorts first — that is the point of sort+reduce).
-    PRECONDITION, not checked: unsorted ids give wrong sums on the
-    non-kernel path.  Off-TPU the sorted layout is exploited with the
-    scatter-free prefix-sum realization."""
-    mode = _kernel_enabled()
-    if mode is False:
-        return _sr.segment_sum_sorted(values, seg_ids, num_segments)
-    return _sr.segment_sum(
-        values, seg_ids, num_segments, interpret=mode is None
-    )
-
-
 def _split_i64(a) -> tuple:
     """int64 host array -> (lo, hi) int32 bit halves via uint64 wraparound
     (negative values split/compare exactly; jnp under x64-off would narrow)."""
@@ -122,10 +115,9 @@ def _split_i64(a) -> tuple:
 
 def table_lookup(cell_keys, cell_starts, table_keys, table_starts, table_occ):
     """Row index of each ``(key, start)`` cell in a device window table
-    (``capacity`` = miss) — the match half of the table's insert/accumulate
-    (the accumulate half dispatches through :func:`scatter_add`).  Keys and
-    starts are int64 on the host; the kernel and its reference compare int32
-    lo/hi halves."""
+    (``capacity`` = miss) — the match half of the table's insert/accumulate.
+    Keys and starts are int64 on the host; the kernel and its reference
+    compare int32 lo/hi halves."""
     cells = _split_i64(cell_keys) + _split_i64(cell_starts)
     table = _split_i64(table_keys) + _split_i64(table_starts)
     occ = np.asarray(table_occ, np.int32)
@@ -164,14 +156,6 @@ def batched_table_lookup(
         jnp.asarray(occ),
         interpret=mode is None,
     )
-
-
-@jax.jit
-def scatter_add(table, ids, rows):
-    mode = _kernel_enabled()
-    if mode is False:
-        return _ref.scatter_add_ref(table, ids, rows)
-    return _sr.scatter_add(table, ids, rows, interpret=mode is None)
 
 
 @jax.jit

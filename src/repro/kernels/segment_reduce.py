@@ -3,27 +3,31 @@
 The hot path of keyed per-slot state updates (``repro.keyed``): instead of
 every owner scanning the whole chunk masked (the S2 masked full-scan
 baseline, O(num_cells * m) work), the chunk is sorted by cell id and reduced
-segment-at-a-time.  ``segment_sum`` is what the keyed engine's device path
-drives today (the host engine then merges the per-cell partials into its
-host-side store); ``scatter_add`` is the second half of the pair — folding
-partials into a device-resident state table — shipped and cross-checked now
-so the ROADMAP's device-resident window-table follow-up has its kernel, but
-not yet on the engine's hot path.
+segment-at-a-time.  The keyed engine's device path drives the pure-XLA
+:func:`segment_sum_sorted` (prefix sum + gather over the sorted chunk).
+The Pallas ``segment_sum`` serves :func:`repro.kernels.ops.segment_sum`;
+``scatter_add`` folds partials into a device-resident state table.  Neither
+kernel is on the engine's hot path.
 
 Both kernels share one TPU-friendly trick: a row block of ``br`` items is
 reduced against all ``S`` segments with a single one-hot matmul
-``partial[S, d] = onehot[br, S]^T @ values[br, d]`` — an MXU contraction
+``partial[S, d] = onehot[S, br] @ values[br, d]`` — an MXU contraction
 instead of a per-row scatter — and the sequential TPU grid accumulates
 partials into the (block-constant) output, initialized on the first step.
-Sorting is not required for correctness (the one-hot contraction is
-order-blind) but the sorted layout is what makes the row blocks touch few
-distinct segments, which is what the compiled kernel's locality wants; the
-algorithm layer (:mod:`repro.keyed.kernels`) always sorts first.
+The contraction is order-blind, so sorting is not required for correctness.
 
-Integer inputs stay integer end-to-end (``preferred_element_type`` pins an
-i32 accumulator) so the keyed engine's bit-exactness contract holds through
-the kernel.  Row counts are padded to the block size with an out-of-range
-cell id, which the one-hot encoding maps to zero contribution.
+The one-hot holds every segment, so a block costs ``O(br * S)`` and the
+output block is ``[S, d]`` in VMEM: these kernels suit a few thousand
+segments.  The keyed engine's per-chunk cell reduction runs the scatter-free
+:func:`segment_sum_sorted` instead, which has no such bound.
+
+The MXU has no int32 contraction.  Integer inputs are split into four bytes,
+each contracted in bfloat16 with a float32 accumulator (a block of at most
+256 rows of bytes sums below 2**24, so every partial is exact), and the
+bytes are recombined with int32 wraparound — so the keyed engine's
+bit-exactness contract holds through the kernel.  Row counts are padded to
+the block size with an out-of-range cell id, which the one-hot encoding maps
+to zero contribution.
 """
 
 from __future__ import annotations
@@ -41,16 +45,26 @@ def _acc_dtype(dtype):
 
 def _onehot_partial(ids_block, values_block, num_segments, acc_dtype):
     """``[S, d]`` partial: one-hot of ids (rows beyond ``num_segments`` drop
-    out) contracted against the value rows on the MXU."""
+    out) contracted against the value rows on the MXU.  Integer values go
+    through the exact byte split described in the module docstring."""
     br = values_block.shape[0]
-    seg = jax.lax.broadcasted_iota(jnp.int32, (br, num_segments), 1)
-    onehot = (ids_block[:, None] == seg).astype(acc_dtype)
-    return jax.lax.dot_general(
-        onehot,
-        values_block.astype(acc_dtype),
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )
+    seg = jax.lax.broadcasted_iota(jnp.int32, (num_segments, br), 0)
+    hit = ids_block[None, :] == seg
+    if acc_dtype == jnp.float32:
+        return jnp.dot(
+            hit.astype(jnp.float32), values_block.astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
+    if br > 256:
+        raise ValueError(f"integer blocks hold at most 256 rows, got {br}")
+    onehot = hit.astype(jnp.bfloat16)
+    values = values_block.astype(jnp.int32)
+    out = jnp.zeros((num_segments, values.shape[1]), jnp.int32)
+    for k in range(4):
+        byte = ((values >> (8 * k)) & 0xFF).astype(jnp.bfloat16)
+        part = jnp.dot(onehot, byte, preferred_element_type=jnp.float32)
+        out = out + (part.astype(jnp.int32) << (8 * k))
+    return out
 
 
 def segment_sum_sorted(values, seg_ids, num_segments: int):
@@ -90,7 +104,7 @@ def _segment_sum_kernel(ids_ref, vals_ref, out_ref, *, num_segments: int):
 
 def segment_sum(
     values, seg_ids, num_segments: int, *, block_rows: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """``out[s, :] = sum over rows r with seg_ids[r] == s of values[r, :]``.
 
@@ -137,7 +151,7 @@ def _scatter_add_kernel(ids_ref, table_ref, rows_ref, out_ref, *,
 
 
 def scatter_add(
-    table, ids, rows, *, block_rows: int = 128, interpret: bool = True,
+    table, ids, rows, *, block_rows: int = 128, interpret: bool = False,
 ):
     """``out = table; out[ids[r], :] += rows[r, :]`` with repeats allowed.
 

@@ -82,7 +82,7 @@ def _kernel(
 
 
 def ssd_scan(
-    x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = True,
+    x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = False,
 ):
     """x [B,H,S,P]; dt [B,H,S] fp32; A [H] fp32 (negative); Bm/Cm [B,H,S,N].
 

@@ -6,8 +6,9 @@ Two interchangeable implementations:
 
 * ``"segment"`` — the hot path, O(m log m + cells) work: stable
   sort-by-cell followed by a segment reduce.  When the Pallas kernels are
-  active (TPU, or forced via ``use_kernels``) this is the device sort
-  feeding :func:`repro.kernels.segment_reduce.segment_sum`; otherwise it is
+  active (TPU, or forced via ``use_kernels``) this runs on the device: an
+  XLA sort feeding the scatter-free prefix-sum reduction
+  :func:`repro.kernels.segment_reduce.segment_sum_sorted`.  Otherwise it is
   the same algorithm in numpy's C kernels (radix sort + prefix-sum
   difference), the honest CPU realization.
 * ``"masked"`` — the S2 masked full-scan baseline, shaped exactly like
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels import segment_reduce as sr
 
 IMPLS = ("segment", "masked")
 
@@ -70,9 +72,9 @@ def sort_by_cell(cell_ids, values):
 
 @functools.partial(jax.jit, static_argnames=("num_cells",))
 def _device_segment_path(cell_ids, values, num_cells: int):
-    # TPU shape of the hot path: device sort feeding the Pallas kernel
+    # device shape of the hot path: XLA sort feeding the prefix-sum reduce
     ids_sorted, vals_sorted = sort_by_cell(cell_ids, values)
-    return ops.segment_sum_sorted(vals_sorted, ids_sorted, num_cells)
+    return sr.segment_sum_sorted(vals_sorted, ids_sorted, num_cells)
 
 
 def _host_segment_path(cell_ids, values, num_cells: int):

@@ -38,9 +38,8 @@ Realizations (the CPU perf-cliff rule of :mod:`repro.keyed.kernels`)
     sort/scatter lowering loses to numpy's C kernels by an order of
     magnitude here).  When the Pallas kernels are active, lookup dispatches
     to :func:`repro.kernels.ops.table_lookup` — the one-hot full-scan match
-    kernel (``kernels/hash_table.py``) — and the accumulate half is the
-    ``scatter_add`` kernel shipped with the segment-reduce pair.  All paths
-    produce bit-identical tables.
+    kernel (``kernels/hash_table.py``); claims and the accumulate half stay
+    in numpy.  All paths produce bit-identical tables.
 """
 
 from __future__ import annotations

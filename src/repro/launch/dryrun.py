@@ -1,13 +1,15 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and record memory/cost/roofline inputs.
 
-The two lines above MUST stay first: jax locks the device count at first
-backend initialization, and the dry-run needs 512 placeholder host devices so
-`jax.make_mesh` can build the (2,16,16) production mesh.  This flag is set
-ONLY here (smoke tests and benchmarks see 1 device).
+The lines above MUST stay first: jax locks the platform and the device count
+at first backend initialization, and the dry-run needs 512 placeholder host
+devices on the CPU backend so `jax.make_mesh` can build the (2,16,16)
+production mesh.  These flags are set ONLY here (smoke tests and benchmarks
+see 1 device), and the dry-run never takes an accelerator.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch codeqwen1.5-7b \

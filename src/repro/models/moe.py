@@ -203,11 +203,6 @@ def moe_ffn_a2a(
     Weight layout (see launch.sharding): w_gate/w_up [E("data"), d, ff("model")],
     w_down [E("data"), ff("model"), d]; router replicated.
     """
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map  # type: ignore
-
     mesh = rules.mesh
     ep = "data"
     tp = rules.tp_axis
@@ -263,7 +258,7 @@ def moe_ffn_a2a(
 
     from jax.sharding import PartitionSpec as P
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

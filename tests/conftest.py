@@ -1,30 +1,11 @@
-"""Pytest bootstrap: provide `hypothesis` from the bundled fallback when the
-real package is not installed (the CI container ships JAX but not hypothesis),
-and dump the flight-recorder black box on the first test failure (CI uploads
-``results/blackbox/`` as the ``tier1-blackbox`` artifact).
+"""Pytest bootstrap: dump the flight-recorder black box on the first test
+failure (CI uploads ``results/blackbox/`` as the ``tier1-blackbox``
+artifact).
 """
 
 import os
-import sys
-import types
 
 import pytest
-
-try:  # real hypothesis wins whenever it is available
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_fallback as _hf
-
-    _mod = types.ModuleType("hypothesis")
-    _mod.given = _hf.given
-    _mod.settings = _hf.settings
-    _st = types.ModuleType("hypothesis.strategies")
-    for _name in ("integers", "floats", "lists", "sampled_from", "booleans"):
-        setattr(_st, _name, getattr(_hf, _name))
-    _mod.strategies = _st
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _st
 
 
 _BLACKBOX_DUMPED = False

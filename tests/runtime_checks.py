@@ -12,6 +12,8 @@ failure->shrink / recovery->grow path and the compiled-step cache.
 import os
 import shutil
 
+# host devices only: this process must never take the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", "")

@@ -7,6 +7,8 @@ see 1 device).  Exits non-zero on the first failure.
 
 import os
 
+# host devices only: this process must never take the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", "")

@@ -34,7 +34,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (B, Hq, Sq, hd), dtype)
         k = jax.random.normal(ks[1], (B, Hkv, Skv, hd), dtype)
         v = jax.random.normal(ks[2], (B, Hkv, Skv, hd), dtype)
-        out = flash_attention(q, k, v, causal=True)
+        out = flash_attention(q, k, v, causal=True, interpret=True)
         want = ref.flash_attention_ref(
             q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
             causal=True,
@@ -49,7 +49,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (1, 2, 256, 64), jnp.float32)
         k = jax.random.normal(ks[1], (1, 2, 256, 64), jnp.float32)
         v = jax.random.normal(ks[2], (1, 2, 256, 64), jnp.float32)
-        out = flash_attention(q, k, v, causal=True, window=window)
+        out = flash_attention(q, k, v, causal=True, window=window, interpret=True)
         want = ref.flash_attention_ref(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-5)
 
@@ -58,7 +58,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (1, 2, 128, 64), jnp.float32)
         k = jax.random.normal(ks[1], (1, 2, 128, 64), jnp.float32)
         v = jax.random.normal(ks[2], (1, 2, 128, 64), jnp.float32)
-        out = flash_attention(q, k, v, causal=True, softcap=30.0)
+        out = flash_attention(q, k, v, causal=True, softcap=30.0, interpret=True)
         want = ref.flash_attention_ref(q, k, v, causal=True, softcap=30.0)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-5)
 
@@ -70,7 +70,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (2, 4, 256, 64), jnp.float32)
         k = jax.random.normal(ks[1], (2, 2, 256, 64), jnp.float32)
         v = jax.random.normal(ks[2], (2, 2, 256, 64), jnp.float32)
-        out = flash_attention(q, k, v, causal=True)
+        out = flash_attention(q, k, v, causal=True, interpret=True)
         # model layout is [B, S, H, hd]
         out2 = attend_chunked(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
@@ -90,7 +90,7 @@ class TestDecodeAttention:
         q = jax.random.normal(ks[0], (B, Hq, hd), dtype)
         ck = jax.random.normal(ks[1], (B, Hkv, S, hd), dtype)
         cv = jax.random.normal(ks[2], (B, Hkv, S, hd), dtype)
-        out = decode_attention(q, ck, cv, jnp.int32(valid))
+        out = decode_attention(q, ck, cv, jnp.int32(valid), interpret=True)
         want = ref.decode_attention_ref(
             q.astype(jnp.float32), ck.astype(jnp.float32), cv.astype(jnp.float32),
             valid,
@@ -104,7 +104,7 @@ class TestDecodeAttention:
         q = jax.random.normal(ks[0], (1, 2, 64), jnp.float32)
         ck = jax.random.normal(ks[1], (1, 2, 512, 64), jnp.float32)
         cv = jax.random.normal(ks[2], (1, 2, 512, 64), jnp.float32)
-        out = decode_attention(q, ck, cv, jnp.int32(400), window=128)
+        out = decode_attention(q, ck, cv, jnp.int32(400), window=128, interpret=True)
         want = ref.decode_attention_ref(q, ck, cv, 400, window=128)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-5)
 
@@ -122,7 +122,7 @@ class TestSSDScan:
         A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
         Bm = (jax.random.normal(ks[3], (B, H, S, N)) * 0.3).astype(dtype)
         Cm = (jax.random.normal(ks[4], (B, H, S, N)) * 0.3).astype(dtype)
-        y, h = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
+        y, h = ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
         y_ref, h_ref = ref.ssd_scan_ref(x, dt, A, Bm, Cm)
         t = dict(atol=5e-2, rtol=5e-2) if dtype == jnp.bfloat16 else dict(
             atol=2e-4, rtol=2e-4
@@ -141,7 +141,7 @@ class TestSSDScan:
         A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
         Bm = jax.random.normal(ks[3], (B, H, S, N)) * 0.3
         Cm = jax.random.normal(ks[4], (B, H, S, N)) * 0.3
-        y, h = ssd_scan(x, dt, A, Bm, Cm, chunk=64)
+        y, h = ssd_scan(x, dt, A, Bm, Cm, chunk=64, interpret=True)
         # model layout: [B, S, H, P] / [B, S, G, N]
         y2, h2 = ssd_chunked(
             x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), A,
@@ -161,7 +161,7 @@ class TestMoEGather:
         x = jax.random.normal(ks[0], (T, d), dtype)
         # include dummy rows (== T)
         row_token = jax.random.randint(ks[1], (R,), 0, T + 1).astype(jnp.int32)
-        out = moe_gather(x, row_token)
+        out = moe_gather(x, row_token, interpret=True)
         want = ref.moe_gather_ref(x, row_token)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
@@ -172,7 +172,7 @@ class TestMoEGather:
         x = jax.random.normal(ks[0], (T, d), jnp.float32)
         row_token = jax.random.randint(ks[1], (R,), 0, T + 1).astype(jnp.int32)
         w = jax.random.uniform(ks[2], (R,))
-        buf = moe_gather(x, row_token)
+        buf = moe_gather(x, row_token, interpret=True)
         y = ref.moe_combine_ref(buf, row_token, w, T)
         onehot = (row_token[:, None] == jnp.arange(T)[None, :]).astype(jnp.float32)
         want = jnp.einsum("rt,r,rd->td", onehot, w, buf)

@@ -278,7 +278,7 @@ class TestKernels:
     def test_ops_segment_sum_is_order_blind_and_sorted_path_matches(self):
         """ops.segment_sum must give ref-equal sums for UNSORTED ids on
         every dispatch path; the sorted-precondition fast path
-        (ops.segment_sum_sorted / segment_sum_sorted) must agree once ids
+        (segment_sum_sorted, the keyed device path) must agree once ids
         are sorted."""
         import jax.numpy as jnp
 
@@ -300,12 +300,6 @@ class TestKernels:
             )
         )
         np.testing.assert_array_equal(got_sorted, want)
-        got_ops = np.asarray(
-            ops.segment_sum_sorted(
-                jnp.asarray(vals[order]), jnp.asarray(ids[order]), 7
-            )
-        )
-        np.testing.assert_array_equal(got_ops, want)
 
     def test_empty_and_bad_impl(self):
         out = np.asarray(
@@ -363,7 +357,7 @@ class TestWindowsBitExact:
             r.protocol == "S2-slotmap-handoff" for r in ex.metrics.resizes
         )
 
-    @settings(max_examples=6)
+    @settings(max_examples=6, deadline=None)
     @given(
         st.sampled_from(["tumbling", "sliding", "session"]),
         st.integers(0, 10_000),
