@@ -16,8 +16,9 @@ and the final barrier snapshot must equal the serial oracle
 :func:`repro.core.semantics.keyed_windows` bit for bit.
 
 Every line before the last is labelled with the device kind: the sizes, the
-per-chunk wall time, the compiles (count and seconds), the host<->device
-bytes of each chunk (computed from the shapes the dispatch layer ships),
+per-chunk wall time, the compiles (count and seconds) and the host<->device
+bytes of each chunk (both read from the program's tracer spans:
+``jax.compile``, and the ``bytes`` of the ``*.ship`` / ``*.wait`` spans),
 and the standing and spilled rows at the end.  The last line of standard
 output is one JSON object::
 
@@ -52,40 +53,6 @@ FULL = dict(
     min_standing=2**19, min_table_rows=2**21,
 )
 
-class CompileCounter:
-    """While entered, counts the XLA programs JAX builds (compiled, or
-    loaded from the persistent cache), their seconds, and the persistent
-    cache's hits."""
-
-    COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-    CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        self.programs, self.secs, self.hits = 0, 0.0, 0
-
-    def _on_duration(self, event, secs, **_):
-        if event == self.COMPILE_EVENT:
-            self.programs += 1
-            self.secs += secs
-
-    def _on_event(self, event, **_):
-        if event == self.CACHE_HIT_EVENT:
-            self.hits += 1
-
-    def __enter__(self):
-        import jax
-
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-        return self
-
-    def __exit__(self, *exc):
-        import jax
-
-        jax.monitoring.unregister_event_duration_listener(self._on_duration)
-        jax.monitoring.unregister_event_listener(self._on_event)
-
-
 def _check(ok: bool, what: str) -> None:
     if not ok:
         raise AssertionError(what)
@@ -103,20 +70,30 @@ def _n_cells(keys, starts) -> int:
     return len(np.unique(np.stack([keys, starts], axis=1), axis=0))
 
 
+def _chunk_totals(spans) -> dict:
+    """What a chunk's spans say: the programs JAX built (compiled, or
+    loaded from the persistent cache) and their seconds, and the bytes
+    shipped to the device and brought back."""
+    built = [s for s in spans if s.name == "jax.compile"]
+    return {
+        "compiles": len(built),
+        "compile_s": sum(s.t1 - s.t0 for s in built),
+        "cache_hits": sum(bool(s.args["cached"]) for s in built),
+        "h2d": sum(s.args["bytes"] for s in spans if s.name.endswith(".ship")),
+        "d2h": sum(s.args["bytes"] for s in spans if s.name.endswith(".wait")),
+    }
+
+
 def run(cfg: dict, *, log=print) -> dict:
     """Drive the plane over ``cfg``'s stream, print per-chunk lines through
     ``log``, and check every output against the oracle.  Returns a summary;
     raises ``AssertionError`` on the first check that fails."""
-    with CompileCounter() as compiles:
-        return _run(cfg, log, compiles)
-
-
-def _run(cfg: dict, log, compiles: CompileCounter) -> dict:
     import jax
 
     from repro.core import semantics
     from repro.keyed import KeyedWindowAdapter, WindowSpec, synthetic_keyed_items
     from repro.keyed.windows import expand_panes
+    from repro.obs import Tracer
     from repro.runtime import StreamExecutor
 
     n_chunks, chunk = cfg["num_chunks"], cfg["chunk"]
@@ -136,11 +113,13 @@ def _run(cfg: dict, log, compiles: CompileCounter) -> dict:
         backend="device_table", capacity=cfg["capacity"],
         max_probes=cfg["max_probes"], fused=True,
     )
-    ex = StreamExecutor(adapter, degree=cfg["n_w"], chunk_size=chunk)
+    tracer = Tracer(recorder=None)
+    ex = StreamExecutor(adapter, degree=cfg["n_w"], chunk_size=chunk, tracer=tracer)
     schedule = {n_chunks // 3: cfg["resize_to"], 2 * n_chunks // 3: cfg["n_w"]}
     barrier_at = n_chunks // 2
     outs = []
-    totals = {"wall": 0.0, "compiles": 0, "compile_s": 0.0, "h2d": 0, "d2h": 0}
+    totals = {"wall": 0.0, "compiles": 0, "compile_s": 0.0, "cache_hits": 0,
+              "h2d": 0, "d2h": 0}
     for i in range(n_chunks):
         if i in schedule:
             t = time.perf_counter()
@@ -161,36 +140,26 @@ def _run(cfg: dict, log, compiles: CompileCounter) -> dict:
             spec, part["key"], part["value"], part["ts"],
             np.arange(len(part), dtype=np.int64),
         )
-        programs, secs = compiles.programs, compiles.secs
+        tracer.reset()
         t = time.perf_counter()
         out = jax.block_until_ready(ex.process(part))
         wall = time.perf_counter() - t
+        got = _chunk_totals(tracer.spans)
         outs.append(out)
         late = out["late"]
         live = len(panes[0]) - len(late["key"])
         # a late assignment's cell is closed, so no live assignment shares it
         cells = _n_cells(panes[0], panes[4]) - _n_cells(late["key"], late["start"])
         rows = ex.degree * cfg["capacity"]
-        # what the dispatch layer ships: reduce_by_cell sends int32 ids and
-        # (value, 1) pairs and returns int32 (sum, count) per cell; the
-        # batched lookup sends five int32 cell planes and six int32 table
-        # planes (owner, key lo/hi, start lo/hi, occupancy) and returns one
-        # int32 row per cell
-        h2d = 12 * live + 20 * cells + 24 * rows
-        d2h = 12 * cells
-        n_comp = compiles.programs - programs
-        comp_s = compiles.secs - secs
         totals["wall"] += wall
-        totals["compiles"] += n_comp
-        totals["compile_s"] += comp_s
-        totals["h2d"] += h2d
-        totals["d2h"] += d2h
+        for k, v in got.items():
+            totals[k] += v
         log(f"chunk {i}: shards={ex.degree} table_rows={rows} "
             f"assignments={live} cells={cells} "
             f"emitted={len(out['emissions']['key'])} "
             f"late={len(late['key'])} wall={wall:.6f}s "
-            f"compiles={n_comp} compile_s={comp_s:.3f} "
-            f"h2d_bytes={h2d} d2h_bytes={d2h}")
+            f"compiles={got['compiles']} compile_s={got['compile_s']:.3f} "
+            f"h2d_bytes={got['h2d']} d2h_bytes={got['d2h']}")
     state = ex.snapshot_barrier()
 
     t = time.perf_counter()
@@ -228,7 +197,7 @@ def _run(cfg: dict, log, compiles: CompileCounter) -> dict:
     log(f"state: standing_rows={standing} spill_rows={spill} "
         f"table_rows={table_rows} shards={ex.degree}")
     log(f"totals: chunk_wall_s={totals['wall']:.6f} compiles={totals['compiles']} "
-        f"compile_s={totals['compile_s']:.3f} cache_hits={compiles.hits} "
+        f"compile_s={totals['compile_s']:.3f} cache_hits={totals['cache_hits']} "
         f"h2d_bytes_per_chunk={totals['h2d'] // n_chunks} "
         f"d2h_bytes_per_chunk={totals['d2h'] // n_chunks}")
     _check(standing >= cfg["min_standing"],

@@ -211,5 +211,6 @@ def batched_table_lookup(
         out_specs=pl.BlockSpec((1, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         interpret=interpret,
+        name="batched_table_lookup",
     )(*cells, *table)
     return out[0, :n]

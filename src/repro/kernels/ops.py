@@ -24,6 +24,7 @@ from repro.kernels import moe_dispatch as _mk
 from repro.kernels import ref as _ref
 from repro.kernels import segment_reduce as _sr
 from repro.kernels import ssd_scan as _sk
+from repro.obs.trace import NULL_TRACER
 
 _MODE = "auto"  # "auto" | "kernel" | "ref" | "interpret"
 
@@ -132,30 +133,48 @@ def table_lookup(cell_keys, cell_starts, table_keys, table_starts, table_occ):
     )
 
 
+def ship(tracer, name: str, *arrays) -> tuple:
+    """Hand host arrays to the device under a ``<name>.ship`` span whose
+    ``bytes`` is what crosses to the device: the sum of their ``nbytes``."""
+    with tracer.span(name + ".ship", bytes=sum(a.nbytes for a in arrays)):
+        return tuple(jnp.asarray(a) for a in arrays)
+
+
+def wait(tracer, name: str, x) -> np.ndarray:
+    """Block on a device result and bring it to the host under a
+    ``<name>.wait`` span whose ``bytes`` is the result's size."""
+    with tracer.span(name + ".wait", bytes=x.nbytes):
+        return np.asarray(x)
+
+
 def batched_table_lookup(
     cell_owners, cell_keys, cell_starts,
-    row_owners, table_keys, table_starts, table_occ,
-):
+    row_owners, table_keys, table_starts, table_occ, *, tracer=NULL_TRACER,
+) -> np.ndarray:
     """Global row of each ``(owner, key, start)`` cell in an all-shard
     batched window table (shard-major stacked planes; ``n_w * capacity`` =
     miss) — ONE dispatch for every shard's cells, the fused plane's
     replacement for ``n_w`` per-shard :func:`table_lookup` calls.  Owner ids
     are small ints and ship as a single int32 plane; keys/starts split into
-    lo/hi int32 halves exactly like :func:`table_lookup`."""
-    cells = (np.asarray(cell_owners, np.int32),) \
-        + _split_i64(cell_keys) + _split_i64(cell_starts)
-    table = (np.asarray(row_owners, np.int32),) \
-        + _split_i64(table_keys) + _split_i64(table_starts)
-    occ = np.asarray(table_occ, np.int32)
+    lo/hi int32 halves exactly like :func:`table_lookup`.  ``tracer`` times
+    the host planes' packing, their shipping, the kernel's dispatch and the
+    wait for its rows (``lookup.pack`` / ``.ship`` / ``.dispatch`` /
+    ``.wait``)."""
+    with tracer.span("lookup.pack"):
+        cells = (np.asarray(cell_owners, np.int32),) \
+            + _split_i64(cell_keys) + _split_i64(cell_starts)
+        table = (np.asarray(row_owners, np.int32),) \
+            + _split_i64(table_keys) + _split_i64(table_starts)
+        occ = np.asarray(table_occ, np.int32)
     mode = _kernel_enabled()
     if mode is False:
-        return _ref.batched_table_lookup_ref(cells, table, occ)
-    return _ht.batched_table_lookup(
-        tuple(jnp.asarray(c) for c in cells),
-        tuple(jnp.asarray(t) for t in table),
-        jnp.asarray(occ),
-        interpret=mode is None,
-    )
+        return np.asarray(_ref.batched_table_lookup_ref(cells, table, occ))
+    dev = ship(tracer, "lookup", *cells, *table, occ)
+    with tracer.span("lookup.dispatch"):
+        out = _ht.batched_table_lookup(
+            dev[:5], dev[5:10], dev[10], interpret=mode is None,
+        )
+    return wait(tracer, "lookup", out)
 
 
 @jax.jit

@@ -544,6 +544,7 @@ class KeyedWindowAdapter(PatternAdapter):
                         np.stack([v_l, np.ones_like(v_l)], axis=1),
                         len(cells),
                         impl=self.impl,
+                        tracer=self.tracer,
                     ),
                     np.int64,
                 )
@@ -561,7 +562,7 @@ class KeyedWindowAdapter(PatternAdapter):
                     spill = self._batched.update(
                         c_owners, c_keys, c_starts, c_starts + size,
                         partial[:, 0], partial[:, 1],
-                        touch_ts=prep["wm_ts"],
+                        touch_ts=prep["wm_ts"], tracer=self.tracer,
                     )
                     if spill is not None:
                         self._merge_per_shard(*spill)
@@ -654,16 +655,15 @@ class KeyedWindowAdapter(PatternAdapter):
             # case): the slot-dict walk was the residual O(n_w) term
             if any(eng.store.slots):
                 rows.extend(eng._store_due())
+        d = None
         if self._batched is not None:
-            d = self._batched.take_due(wm)
-            rows.extend(
-                zip(d[1].tolist(), d[2].tolist(), d[3].tolist(),
-                    d[4].tolist(), d[5].tolist())
-            )
-            if self.ttl is not None:
-                e = self._batched.evict_idle(wm, self.ttl)
-                # idle rows change tier, not value: host stores absorb them
-                self._merge_per_shard(e[0], e[1], e[2], e[3], e[4], e[5])
+            with self.tracer.span("take_due") as sp:
+                d = self._batched.take_due(wm)
+                sp.note(rows=len(d[0]))
+                if self.ttl is not None:
+                    e = self._batched.evict_idle(wm, self.ttl)
+                    # idle rows change tier, not value: host stores absorb them
+                    self._merge_per_shard(e[0], e[1], e[2], e[3], e[4], e[5])
         early = _emission_dict([])
         if ticked:
             for eng in shards:
@@ -690,7 +690,16 @@ class KeyedWindowAdapter(PatternAdapter):
                 early = _emission_dict(
                     KeyedWindowEngine._merge_fire(open_rows)
                 )
-        return _emission_dict(KeyedWindowEngine._merge_fire(rows)), early
+        with self.tracer.span(
+            "fire", rows=len(rows) + (len(d[0]) if d is not None else 0)
+        ):
+            if d is not None:
+                rows.extend(
+                    zip(d[1].tolist(), d[2].tolist(), d[3].tolist(),
+                        d[4].tolist(), d[5].tolist())
+                )
+            emissions = _emission_dict(KeyedWindowEngine._merge_fire(rows))
+        return emissions, early
 
     def resize_live(self, n_old: int, n_new: int) -> ResizeInfo:
         """Row-level slot migration between live shards.

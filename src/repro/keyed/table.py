@@ -50,6 +50,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from repro.keyed.store import HASH_MULTIPLIER
+from repro.obs.trace import NULL_TRACER
 
 #: second mix constant (64-bit golden ratio) — decorrelates the window start
 #: from the key before the multiplicative hash spreads the cell over rows
@@ -534,13 +535,15 @@ class BatchedWindowTable:
         return owners[:, None] * self.capacity + probes
 
     # -- batched lookup --------------------------------------------------------
-    def lookup(self, owners, cell_keys, cell_starts) -> np.ndarray:
+    def lookup(self, owners, cell_keys, cell_starts, *,
+               tracer=NULL_TRACER) -> np.ndarray:
         """Global row of each ``(owner, key, start)`` cell, ``-1`` = absent.
 
         One dispatch for ALL shards: the Pallas grid-over-shards full-scan
-        match kernel (:func:`repro.kernels.ops.batched_table_lookup`) when
-        the kernels are active, the numpy probe-window realization on CPU
-        (the XLA-CPU-cliff rule); both return the identical unique row.
+        match kernel (:func:`repro.kernels.ops.batched_table_lookup`, which
+        times its stages on ``tracer``) when the kernels are active, the
+        numpy probe-window realization on CPU (the XLA-CPU-cliff rule); both
+        return the identical unique row.
         """
         ck = np.asarray(cell_keys, np.int64)
         cs = np.asarray(cell_starts, np.int64)
@@ -550,13 +553,10 @@ class BatchedWindowTable:
         from repro.kernels import ops  # late import: keyed.store must not pull jax
 
         if ops.kernels_active():
-            rows = np.asarray(
-                ops.batched_table_lookup(
-                    ow, ck, cs, self.row_owner, self._fkey, self._fstart,
-                    self._focc,
-                ),
-                np.int64,
-            )
+            rows = ops.batched_table_lookup(
+                ow, ck, cs, self.row_owner, self._fkey, self._fstart,
+                self._focc, tracer=tracer,
+            ).astype(np.int64)
             return np.where(rows >= self.total_rows, np.int64(-1), rows)
         cand = self._probe_window(ow, cell_hash(ck, cs, self.capacity))
         m = (
@@ -586,14 +586,15 @@ class BatchedWindowTable:
     # -- the whole-plane fused update ------------------------------------------
     def update(
         self, owners, cell_keys, cell_starts, cell_ends, value_sums, counts,
-        touch_ts: int,
+        touch_ts: int, *, tracer=NULL_TRACER,
     ) -> Optional[Tuple[np.ndarray, ...]]:
         """Accumulate ALL shards' per-cell partials in one pass: a single
         lookup dispatch, a single claim loop, a single scatter-add over the
         stacked planes.  Cells must be canonically sorted and duplicate-free
         across the whole batch.  Returns the spill as ``(owner, key, start,
         end, value, count)`` arrays (``None`` when nothing spilled) — the
-        caller merges each spilled cell into its owner's host tier."""
+        caller merges each spilled cell into its owner's host tier.
+        ``tracer`` gets a ``lookup`` span and a ``claim`` span."""
         ow = np.asarray(owners, np.int64)
         ck = np.asarray(cell_keys, np.int64)
         cs = np.asarray(cell_starts, np.int64)
@@ -602,11 +603,14 @@ class BatchedWindowTable:
         cn = np.asarray(counts, np.int64)
         if not len(ck):
             return None
-        rows = self.lookup(ow, ck, cs)
+        with tracer.span("lookup", cells=len(ck)):
+            rows = self.lookup(ow, ck, cs, tracer=tracer)
         miss = rows < 0
-        self.stats.hits += int((~miss).sum())
-        if miss.any():
-            rows[miss] = self._claim(ow[miss], ck[miss], cs[miss], ce[miss])
+        n_miss = int(miss.sum())
+        self.stats.hits += len(ck) - n_miss
+        if n_miss:
+            with tracer.span("claim", cells=n_miss):
+                rows[miss] = self._claim(ow[miss], ck[miss], cs[miss], ce[miss])
         ok = rows >= 0
         r = rows[ok]
         np.add.at(self._fvalue, r, vs[ok])

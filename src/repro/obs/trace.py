@@ -17,8 +17,24 @@ Overhead contract
     attribute load + call per stage and allocates nothing — the fused-plane
     benchmark gates this against the un-instrumented PR 5 baselines.  The
     **enabled** path allocates one small object per span and reads the
-    clock twice; ``benchmarks/keyed_fused.py`` reports (and CI bounds) the
-    measured enabled/disabled ratio.
+    clock twice (and, on a wall clock, enters one profiler annotation);
+    ``benchmarks/keyed_fused.py`` reports (and CI bounds) the measured
+    enabled/disabled ratio.
+
+JAX programs, collector passes and the profiler's clock
+    An enabled tracer on a :class:`~repro.obs.clock.WallClock` also enters a
+    ``jax.profiler.TraceAnnotation`` for every span, so a profile taken with
+    ``jax.profiler.start_trace`` shows the program's spans on its host plane,
+    on the device ops' clock.  It records one ``jax.trace`` / ``jax.lower`` /
+    ``jax.compile`` span for each program JAX traces, lowers or builds (from
+    the ``jax.monitoring`` duration events; ``jax.compile`` carries
+    ``cached``: a persistent-cache hit built it), and one ``gc`` span for
+    each pass of Python's collector, on the thread and under the span open
+    there; nothing is recorded on a thread with no open span.  Tracers are
+    held weakly by process-wide listeners, installed once, and JAX's only
+    once the process has imported JAX.  A :class:`~repro.obs.clock.
+    LogicalClock` tracer gets none of this, so simulated traces stay
+    byte-identical.
 
 Event buffers are bounded (``max_events``): a long-running serving process
 keeps the newest events and counts the drop, it never grows without limit.
@@ -36,7 +52,11 @@ checkpoint-restore.
 
 from __future__ import annotations
 
+import gc
+import sys
 import threading
+import time
+import weakref
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
@@ -107,32 +127,55 @@ class CounterRecord:
 class _ActiveSpan:
     """Context manager for one live span (enabled tracer only)."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth", "_state")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth", "_state",
+                 "_outer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args):
         self._tracer = tracer
         self._name = name
         self._args = args
 
+    def note(self, **args) -> None:
+        """Add args that are known only once the span's work is done."""
+        if self._args is None:
+            self._args = args
+        else:
+            self._args.update(args)
+
     def __enter__(self) -> "_ActiveSpan":
         tr = self._tracer
+        ann = None
+        if tr._hooked:
+            cls = _jax_annotation()
+            if cls is not None:
+                ann = cls(self._name)
+        self._ann = ann
         state = tr._thread_state()
         self._state = state
         self._depth = state[1]
-        state[1] += 1
         self._t0 = tr.clock.now()
+        self._outer = state[2]
+        state[2] = self
+        state[1] += 1
+        if ann is not None:
+            ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         tr = self._tracer
         t1 = tr.clock.now()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         state = self._state
         state[1] -= 1
+        state[2] = self._outer
         tr._append(
             tr.spans,
             SpanRecord(self._name, self._t0, t1, state[0], self._depth,
                        self._args),
         )
+        if tr._pending:
+            tr._flush_pending()
 
 
 class Tracer:
@@ -167,6 +210,13 @@ class Tracer:
         self._local = threading.local()
         self._next_tid = 0
         self._n_events = 0
+        #: JAX-build and collector spans waiting for the next span's exit
+        #: (a collector pass may start inside ``_append``'s lock)
+        self._pending: List[SpanRecord] = []
+        self._hooked = isinstance(self.clock, WallClock)
+        if self._hooked:
+            _HOOKED.add(self)
+            _install_gc_hook()
 
     @property
     def dropped(self) -> int:
@@ -219,15 +269,52 @@ class Tracer:
         )
 
     # -- internals -----------------------------------------------------------
-    def _thread_state(self) -> List[int]:
-        """``[tid, depth]`` for the calling thread (created on first use)."""
+    def _thread_state(self) -> list:
+        """``[tid, depth, innermost open span, recent hook spans]`` for the
+        calling thread (created on first use)."""
         state = getattr(self._local, "state", None)
         if state is None:
             with self._lock:
-                state = [self._next_tid, 0]
+                state = [self._next_tid, 0, None, deque(maxlen=4096)]
                 self._next_tid += 1
             self._local.state = state
         return state
+
+    def _record_hook(self, name: str, secs: float, args: dict) -> None:
+        """One span of ``secs`` that ends now, under the span open on the
+        calling thread: a JAX build or a collector pass.  An earlier such
+        span that it contains (a trace nested in a trace, a collector pass
+        inside a compile) moves one level down, so self times stay exact."""
+        state = getattr(self._local, "state", None)
+        if state is None or state[2] is None:
+            return
+        t1 = self.clock.now()
+        t0 = max(t1 - secs, state[2]._t0)
+        depth = state[1]
+        inside = []
+        for rec in reversed(state[3]):  # in the order they ended
+            if rec.t1 < t0:
+                break
+            if rec.depth < depth:
+                continue
+            if rec.t0 < t0:
+                # it ended after this one began by the two clocks' jitter
+                # alone (one thread runs them in turn): start after it
+                t0 = rec.t1
+                break
+            inside.append(rec)
+        for rec in inside:
+            if rec.t0 >= t0:
+                rec.depth += 1
+        rec = SpanRecord(name, t0, t1, state[0], depth, args)
+        state[3].append(rec)
+        self._pending.append(rec)
+
+    def _flush_pending(self) -> None:
+        with self._lock:
+            pending, self._pending = self._pending, []
+        for rec in pending:
+            self._append(self.spans, rec)
 
     def _append(self, buf: List, rec) -> None:
         recorder = self.recorder
@@ -251,6 +338,7 @@ class Tracer:
     def reset(self) -> None:
         """Drop buffered events (benchmarks reset after warmup)."""
         with self._lock:
+            self._pending = []
             self.spans.clear()
             self.instants.clear()
             self.counters.clear()
@@ -285,6 +373,9 @@ class _NullSpan:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        return None
+
+    def note(self, **args) -> None:
         return None
 
 
@@ -337,6 +428,82 @@ class NullTracer:
 
 #: the process-wide disabled tracer — instrumented modules default to this
 NULL_TRACER = NullTracer()
+
+
+# -- JAX builds, collector passes and profiler annotations -------------------
+# The listeners are process-wide (``jax.monitoring`` and ``gc.callbacks``
+# are), so one set serves every enabled wall-clock tracer, held weakly.
+
+_HOOKED: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_INSTALL_LOCK = threading.Lock()
+#: ``jax.profiler.TraceAnnotation`` once the JAX listeners are installed
+_annotation_cls = None
+_gc_installed = False
+_gc_t0: Optional[float] = None
+#: a persistent-cache hit seen since the last backend-compile event
+_cache_hit = False
+
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_jax_duration(event, secs, fun_name="", **_):
+    global _cache_hit
+    name = _JAX_EVENTS.get(event)
+    if name is None:
+        return
+    args = {"fun": fun_name}
+    if name == "jax.compile":
+        args["cached"] = _cache_hit
+        _cache_hit = False
+    for tr in list(_HOOKED):
+        tr._record_hook(name, secs, args)
+
+
+def _on_jax_event(event, **_):
+    global _cache_hit
+    if event == _CACHE_HIT_EVENT:
+        _cache_hit = True
+
+
+def _on_gc(phase, info):
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+    elif _gc_t0 is not None:
+        secs = time.perf_counter() - _gc_t0
+        _gc_t0 = None
+        for tr in list(_HOOKED):
+            tr._record_hook("gc", secs, {"generation": info["generation"]})
+
+
+def _install_gc_hook() -> None:
+    global _gc_installed
+    with _INSTALL_LOCK:
+        if not _gc_installed:
+            gc.callbacks.append(_on_gc)
+            _gc_installed = True
+
+
+def _jax_annotation():
+    """``jax.profiler.TraceAnnotation``, installing the JAX listeners on the
+    first call after the process has imported JAX; None before (a process
+    that never imports JAX builds no programs to record)."""
+    global _annotation_cls
+    if _annotation_cls is None and "jax" in sys.modules:
+        with _INSTALL_LOCK:
+            if _annotation_cls is None:
+                import jax
+
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_jax_duration)
+                jax.monitoring.register_event_listener(_on_jax_event)
+                _annotation_cls = jax.profiler.TraceAnnotation
+    return _annotation_cls
 
 
 class FlightRecorder:
