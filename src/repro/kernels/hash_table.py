@@ -35,6 +35,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+#: cells per block of the lookup kernels' grid (a lane row of 128)
+BLOCK_CELLS = 128
+
 
 def _match_candidates(
     cell_lo_hi, table_lo_hi, occ, base: int, capacity: int,
@@ -77,7 +80,7 @@ def _table_lookup_kernel(
 
 
 def table_lookup(
-    cell_lo_hi, table_lo_hi, occ, *, block_cells: int = 128,
+    cell_lo_hi, table_lo_hi, occ, *, block_cells: int = BLOCK_CELLS,
     block_table: int = 512, interpret: bool = False,
 ):
     """Row index of each cell in the table, ``capacity`` = miss.
@@ -167,7 +170,7 @@ def _batched_table_lookup_kernel(
 
 
 def batched_table_lookup(
-    cell_planes, table_planes, occ, *, block_cells: int = 128,
+    cell_planes, table_planes, occ, *, block_cells: int = BLOCK_CELLS,
     block_table: int = 512, interpret: bool = False,
 ):
     """Global row of each cell in an ``n_w``-shard batched table (stacked

@@ -114,25 +114,6 @@ def _split_i64(a) -> tuple:
     return lo, hi
 
 
-def table_lookup(cell_keys, cell_starts, table_keys, table_starts, table_occ):
-    """Row index of each ``(key, start)`` cell in a device window table
-    (``capacity`` = miss) — the match half of the table's insert/accumulate.
-    Keys and starts are int64 on the host; the kernel and its reference
-    compare int32 lo/hi halves."""
-    cells = _split_i64(cell_keys) + _split_i64(cell_starts)
-    table = _split_i64(table_keys) + _split_i64(table_starts)
-    occ = np.asarray(table_occ, np.int32)
-    mode = _kernel_enabled()
-    if mode is False:
-        return _ref.table_lookup_ref(cells, table, occ)
-    return _ht.table_lookup(
-        tuple(jnp.asarray(c) for c in cells),
-        tuple(jnp.asarray(t) for t in table),
-        jnp.asarray(occ),
-        interpret=mode is None,
-    )
-
-
 def ship(tracer, name: str, *arrays) -> tuple:
     """Hand host arrays to the device under a ``<name>.ship`` span whose
     ``bytes`` is what crosses to the device: the sum of their ``nbytes``."""
@@ -147,6 +128,57 @@ def wait(tracer, name: str, x) -> np.ndarray:
         return np.asarray(x)
 
 
+_LOOKUP_STATIC = ("block_cells", "block_table", "interpret")
+_table_lookup = jax.jit(_ht.table_lookup, static_argnames=_LOOKUP_STATIC)
+_batched_table_lookup = jax.jit(
+    _ht.batched_table_lookup, static_argnames=_LOOKUP_STATIC
+)
+
+
+def _planes(*columns) -> tuple:
+    """The lookup's int32 planes: an owner column as it is, then the int64
+    key and start columns split into lo/hi halves."""
+    *owner, keys, starts = columns
+    return tuple(np.asarray(o, np.int32) for o in owner) \
+        + _split_i64(keys) + _split_i64(starts)
+
+
+def _lookup(program, ref, cell_columns, table_columns, table_occ, tracer):
+    """Run one lookup kernel on host columns and return the row of each
+    cell.  The cell planes are padded on the host to a multiple of the
+    kernel's cell block (padding is arbitrary: its rows are sliced off), so
+    the jitted ``program`` sees one shape per padded count and is built
+    once per shape in a process; ``ref``, the jnp reference, runs instead
+    where the kernels are off."""
+    mode = _kernel_enabled()
+    with tracer.span("lookup.pack"):
+        cells = _planes(*cell_columns)
+        table = _planes(*table_columns)
+        occ = np.asarray(table_occ, np.int32)
+        n = len(cells[0])
+        if mode is not False:
+            short = (-n) % _ht.BLOCK_CELLS
+            cells = tuple(np.pad(c, (0, short)) for c in cells)
+    if mode is False:
+        return np.asarray(ref(cells, table, occ))
+    dev = ship(tracer, "lookup", *cells, *table, occ)
+    k = len(cells)
+    with tracer.span("lookup.dispatch"):
+        out = program(dev[:k], dev[k:2 * k], dev[-1], interpret=mode is None)
+    return wait(tracer, "lookup", out)[:n]
+
+
+def table_lookup(cell_keys, cell_starts, table_keys, table_starts, table_occ):
+    """Row index of each ``(key, start)`` cell in a device window table
+    (``capacity`` = miss) — the match half of the table's insert/accumulate.
+    Keys and starts are int64 on the host; the kernel and its reference
+    compare int32 lo/hi halves."""
+    return _lookup(
+        _table_lookup, _ref.table_lookup_ref, (cell_keys, cell_starts),
+        (table_keys, table_starts), table_occ, NULL_TRACER,
+    )
+
+
 def batched_table_lookup(
     cell_owners, cell_keys, cell_starts,
     row_owners, table_keys, table_starts, table_occ, *, tracer=NULL_TRACER,
@@ -157,24 +189,14 @@ def batched_table_lookup(
     replacement for ``n_w`` per-shard :func:`table_lookup` calls.  Owner ids
     are small ints and ship as a single int32 plane; keys/starts split into
     lo/hi int32 halves exactly like :func:`table_lookup`.  ``tracer`` times
-    the host planes' packing, their shipping, the kernel's dispatch and the
-    wait for its rows (``lookup.pack`` / ``.ship`` / ``.dispatch`` /
-    ``.wait``)."""
-    with tracer.span("lookup.pack"):
-        cells = (np.asarray(cell_owners, np.int32),) \
-            + _split_i64(cell_keys) + _split_i64(cell_starts)
-        table = (np.asarray(row_owners, np.int32),) \
-            + _split_i64(table_keys) + _split_i64(table_starts)
-        occ = np.asarray(table_occ, np.int32)
-    mode = _kernel_enabled()
-    if mode is False:
-        return np.asarray(_ref.batched_table_lookup_ref(cells, table, occ))
-    dev = ship(tracer, "lookup", *cells, *table, occ)
-    with tracer.span("lookup.dispatch"):
-        out = _ht.batched_table_lookup(
-            dev[:5], dev[5:10], dev[10], interpret=mode is None,
-        )
-    return wait(tracer, "lookup", out)
+    the host planes' packing and padding, their shipping, the kernel's
+    dispatch and the wait for its rows (``lookup.pack`` / ``.ship`` /
+    ``.dispatch`` / ``.wait``)."""
+    return _lookup(
+        _batched_table_lookup, _ref.batched_table_lookup_ref,
+        (cell_owners, cell_keys, cell_starts),
+        (row_owners, table_keys, table_starts), table_occ, tracer,
+    )
 
 
 @jax.jit
