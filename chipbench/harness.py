@@ -216,18 +216,39 @@ class Window:
 
 # -- the plane ------------------------------------------------------------------
 
+#: the keys of a configuration's ``window`` group that each kind of window
+#: takes, under the names of ``WindowSpec``'s arguments
+WINDOW_PARAMS = {
+    "tumbling": {"size": "size_ms"},
+    "sliding": {"size": "size_ms", "slide": "slide_ms"},
+    "session": {"gap": "gap_ms"},
+}
+
+
+def window_spec(window: dict, *, late_policy: str):
+    """The program's ``WindowSpec`` of a configuration's ``window`` group."""
+    from repro.keyed import WindowSpec
+
+    return WindowSpec(
+        window["kind"], lateness=window["lateness_ms"], late_policy=late_policy,
+        **{arg: window[key] for arg, key in WINDOW_PARAMS[window["kind"]].items()},
+    )
+
+
+def horizon_ms(window: dict) -> int:
+    """The event time after which the first state can fire: a window's
+    size, or a session's gap."""
+    return window["gap_ms"] if window["kind"] == "session" else window["size_ms"]
+
+
 def build_plane(config: dict, *, late_policy: str, tracer=None):
-    from repro.keyed import KeyedWindowAdapter, WindowSpec
+    from repro.keyed import KeyedWindowAdapter
     from repro.runtime import StreamExecutor
 
-    w, p = config["window"], config["plane"]
-    spec = WindowSpec(
-        w["kind"], size=w["size_ms"],
-        slide=w["slide_ms"] if w["kind"] == "sliding" else 0,
-        lateness=w["lateness_ms"], late_policy=late_policy,
-    )
+    p = config["plane"]
     adapter = KeyedWindowAdapter(
-        spec, num_slots=p["num_slots"], impl="segment",
+        window_spec(config["window"], late_policy=late_policy),
+        num_slots=p["num_slots"], impl="segment",
         backend="device_table", capacity=config["capacity"],
         max_probes=p["max_probes"], fused=True,
     )
@@ -253,12 +274,18 @@ def _cols(outs, channel, names) -> np.ndarray:
 def check(outs, snapshot, items, config) -> Dict[str, dict]:
     """Compare what the timed path produced with the plain reference: every
     emission, every late record and the final barrier snapshot."""
-    w = config["window"]
-    slide = w["slide_ms"] if w["kind"] == "sliding" else w["size_ms"]
-    em, late, open_state = reference.keyed_windows(
-        items["key"], items["value"], items["ts"], size=w["size_ms"],
-        slide=slide, lateness=w["lateness_ms"], chunk=config["plane"]["chunk"],
-    )
+    w, chunk = config["window"], config["plane"]["chunk"]
+    if w["kind"] == "session":
+        em, late, open_state = reference.keyed_sessions(
+            items["key"], items["value"], items["ts"], gap=w["gap_ms"],
+            lateness=w["lateness_ms"], chunk=chunk,
+        )
+    else:
+        em, late, open_state = reference.keyed_windows(
+            items["key"], items["value"], items["ts"], size=w["size_ms"],
+            slide=w["slide_ms"] if w["kind"] == "sliding" else w["size_ms"],
+            lateness=w["lateness_ms"], chunk=chunk,
+        )
     got_open = np.stack(
         [np.asarray(snapshot[k], np.int64)
          for k in ("w_key", "w_start", "w_end", "w_value", "w_count")], axis=1,
@@ -331,6 +358,24 @@ def _saturate(src: _Source, k: int, seconds: float, t0: float):
     return done
 
 
+def chunk_cells(items, config) -> np.ndarray:
+    """The distinct cells, or session fragments, that each chunk of
+    ``items`` sends to the plane."""
+    w, chunk = config["window"], config["plane"]["chunk"]
+    if w["kind"] == "session":
+        _, cells = reference.session_shapes(
+            items["key"], items["ts"], gap=w["gap_ms"], lateness=w["lateness_ms"],
+            chunk=chunk,
+        )
+    else:
+        _, cells = reference.chunk_shapes(
+            items["key"], items["ts"], size=w["size_ms"],
+            slide=w["slide_ms"] if w["kind"] == "sliding" else w["size_ms"],
+            lateness=w["lateness_ms"], chunk=chunk,
+        )
+    return cells
+
+
 def _rows(ex) -> Dict[str, int]:
     """The plane's standing rows on the device tier and in the host spill
     tier, from the program's own health gauges."""
@@ -342,15 +387,41 @@ def _rows(ex) -> Dict[str, int]:
             for k in ("resident", "spill")}
 
 
+def _require_device_tier(ex, name: str, window: dict) -> None:
+    """Raise when the plane holds open rows and none of them on the device
+    tier: a cell measures the state on the device, and a plane that keeps it
+    all in the host store would run out a run's time instead."""
+    rows = _rows(ex)
+    if rows["spill"] and not rows["resident"]:
+        raise RuntimeError(
+            f"configuration {name}: after the first chunk the plane keeps none "
+            f"of its open rows of {window['kind']} windows on the device tier "
+            f"({rows['resident']} resident rows, {rows['spill']} in the host "
+            f"store); nothing is measured")
+
+
 def warmup_chunks(stream, config: dict, traffic: dict) -> int:
     """Chunks of set-up before the window: the stream runs for
-    ``warmup_windows`` window sizes of event time, and then for the lateness
-    and the jitter, so that every window that ends by then has fired and the
-    window starts on the standing state of a running deployment."""
+    ``warmup_windows`` horizons of event time (window sizes, or session
+    gaps), and then for the lateness and the jitter, so that every window
+    that ends by then has fired and the window starts on the standing state
+    of a running deployment."""
     w = config["window"]
-    fire_ms = (traffic["warmup_windows"] * w["size_ms"] + w["lateness_ms"]
+    fire_ms = (traffic["warmup_windows"] * horizon_ms(w) + w["lateness_ms"]
                + config["jitter_ms"])
     return math.ceil(fire_ms / stream.chunk_ms()) + 1
+
+
+def apply_overrides(config: dict, traffic: dict, overrides: Optional[dict]) -> None:
+    """Update the configuration's keys and groups, and the traffic mix under
+    ``"traffic"``, in place."""
+    for key, value in (overrides or {}).items():
+        if key == "traffic":
+            traffic.update(value)
+        elif isinstance(value, dict):
+            config[key].update(value)
+        else:
+            config[key] = value
 
 
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
@@ -367,13 +438,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     import jax
 
     bench, cell, config, traffic = load_cell(name)
-    for key, value in (overrides or {}).items():
-        if key == "traffic":
-            traffic.update(value)
-        elif isinstance(value, dict):
-            config[key].update(value)
-        else:
-            config[key] = value
+    apply_overrides(config, traffic, overrides)
     if traffic["loop"] != "saturate":
         raise ValueError(f"unknown loop {traffic['loop']!r}")
     dev = jax.devices()[0]
@@ -398,6 +463,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             t = time.perf_counter()
             src.process(k)
             took.append(time.perf_counter() - t)
+            if k == 0:
+                _require_device_tier(src.ex, cell["config"], config["window"])
         warm_built = (compiles.programs, compiles.secs)
         # a chunk's time once the shapes repeat gives the chunks the window
         # will take; a copy of the plane runs them, and more, first
@@ -478,12 +545,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     else:
         dtrace = devtrace.load(devtrace.find_xplane(TRACE_DIR), anchor)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        w = config["window"]
-        _, cells = reference.chunk_shapes(
-            items["key"], items["ts"], size=w["size_ms"],
-            slide=w["slide_ms"] if w["kind"] == "sliding" else w["size_ms"],
-            lateness=w["lateness_ms"], chunk=chunk,
-        )
+        cells = chunk_cells(items, config)
         win = Window(
             t0=t0, t1=t1, chunks=len(done), cells=cells[warm:warm + len(done)],
             compiles=in_window[0], spans=spans, trace=dtrace,

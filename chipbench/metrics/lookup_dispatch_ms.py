@@ -1,7 +1,7 @@
-"""Self time per chunk, in ms, of the ``lookup.dispatch`` span: the eager
-pads around the lookup kernel, the ``pallas_call``'s build and launch, and
-the slice of its output.  The programs JAX traces, lowers and builds there
-are spans of their own (``compile_ms``), so they are not counted here."""
+"""Self time per chunk, in ms, of the ``lookup.dispatch`` span: the launch
+of the lookup's jitted program, built once per padded count of cells.  A
+program that JAX traces, lowers or builds there, for a padded count it has
+not met, is a span of its own (``compile_ms``), so it is not counted here."""
 
 from chipbench.spans import self_seconds
 

@@ -88,19 +88,14 @@ def test_event_numbers_and_id_counts_follow_beams_proportions():
 @pytest.mark.parametrize("name", CONFIGS)
 def test_cell_counts_vary_from_chunk_to_chunk_and_seed_to_seed(name):
     """The stream is not shaped to the program: the count of distinct
-    (key, window) cells, which sets the shapes the program compiles,
-    differs between chunks and between seeds."""
+    (key, window) cells, or of session fragments, which sets the shapes the
+    program compiles, differs between chunks and between seeds."""
     counts = []
     config = _config(name)
     for seed in (3, 2**31 + 9):
         st = bidstream.BidStream.for_config(config, seed)
-        w = config["window"]
-        slide = w["slide_ms"] if w["kind"] == "sliding" else w["size_ms"]
         items = np.concatenate([st.chunk(k) for k in range(10, 30)])
-        _, cells = reference.chunk_shapes(
-            items["key"], items["ts"], size=w["size_ms"], slide=slide,
-            lateness=w["lateness_ms"], chunk=st.chunk_size,
-        )
+        cells = harness.chunk_cells(items, config)
         assert len(set(cells.tolist())) > 5
         counts.append(cells)
     assert not np.array_equal(counts[0], counts[1])
@@ -112,5 +107,25 @@ def test_warmup_ends_once_its_windows_have_fired(cell):
     warm = harness.warmup_chunks(st, config, traffic)
     ts = np.concatenate([st.chunk(k)["ts"] for k in range(warm)])
     wm = reference.watermarks(ts, st.chunk_size, config["window"]["lateness_ms"])
-    until = traffic["warmup_windows"] * config["window"]["size_ms"]
+    until = traffic["warmup_windows"] * harness.horizon_ms(config["window"])
     assert wm[-2] >= until > wm[-int(200 / st.chunk_ms()) - 3]
+
+
+@pytest.mark.parametrize("name,traffic,params,warm", [
+    ("nexmark_q12_tumble", "saturate_midwindow", {"size": 10000}, 679),
+    ("nexmark_q5_hop", "saturate", {"size": 10000, "slide": 2000}, 171),
+    ("nexmark_q11_session", "saturate", {"gap": 10000}, 454),
+])
+def test_window_spec_and_warmup_follow_the_window_kind(name, traffic, params, warm):
+    """Each kind takes its own parameters from the configuration, and set-up
+    runs for its horizon: a window's size, or a session's gap."""
+    from repro.keyed import WindowSpec
+
+    config = _config(name)
+    with open(os.path.join(harness.BENCH_DIR, "traffic", traffic + ".json")) as f:
+        mix = json.load(f)
+    w = config["window"]
+    assert harness.window_spec(w, late_policy="side") == WindowSpec(
+        w["kind"], lateness=25, late_policy="side", **params)
+    st = bidstream.BidStream.for_config(config, 1)
+    assert harness.warmup_chunks(st, config, mix) == warm

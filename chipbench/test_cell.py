@@ -12,7 +12,7 @@ import time
 import numpy as np
 import pytest
 
-from chipbench import harness
+from chipbench import bidstream, harness
 from repro.keyed import kernels as kk
 from repro.keyed.runtime import KeyedWindowAdapter
 from repro.keyed.table import BatchedWindowTable
@@ -36,6 +36,9 @@ def interpret():
 
 #: the same cell with Q5's shape: hopping windows, keyed by auction
 SLIDING = {"key": "auction", "window": {**TINY["window"], "kind": "sliding"}}
+#: the same cell with Q11's shape: session windows keyed by bidder, with a
+#: gap short enough against the jitter that some bids come late
+SESSION = {"window": {"kind": "session", "gap_ms": 40}}
 
 
 def _run(cell="q12_tumble.saturate", more=None, **kw):
@@ -107,3 +110,38 @@ def test_the_command_refuses_a_machine_without_a_tpu():
     assert p.returncode != 0 and p.stdout == ""
     assert "TPU" in p.stderr
     assert "TPU" in harness.chip_problem(1)
+
+
+def test_session_plane_checks_exact_and_a_dropped_row_shows(interpret):
+    """The program keeps session windows in its host store; driven directly,
+    a dozen chunks check exact, and one emission or late row left out is
+    counted."""
+    _, _, config, traffic = harness.load_cell("q12_tumble.saturate")
+    harness.apply_overrides(config, traffic, {**TINY, **SESSION})
+    stream = bidstream.BidStream.for_config(config, 2**31 + 17)
+    ex = harness.build_plane(config, late_policy="side")
+    chunks = [stream.chunk(k) for k in range(12)]
+    outs = [ex.process(c) for c in chunks]
+    rows = harness._rows(ex)
+    assert rows["resident"] == 0 and rows["spill"] > 0
+    items, snapshot = np.concatenate(chunks), ex.snapshot_barrier()
+    checks = harness.check(outs, snapshot, items, config)
+    assert all(c["value"] == 0 and c["limit"] == 0 for c in checks.values())
+    assert sum(len(o["late"]["key"]) for o in outs) > 0
+    for channel, off in (("emissions", "emission_rows_off"), ("late", "late_rows_off")):
+        k = next(i for i, o in enumerate(outs) if len(o[channel]["key"]))
+        cut = list(outs)
+        cut[k] = {**outs[k], channel: {c: v[1:] for c, v in outs[k][channel].items()}}
+        assert harness.check(cut, snapshot, items, config)[off]["value"] > 0
+
+
+def test_a_plane_with_no_state_on_the_device_stops_after_its_first_chunk(interpret,
+                                                                         monkeypatch):
+    sent = []
+    process = harness._Source.process
+    monkeypatch.setattr(harness._Source, "process",
+                        lambda self, k: (sent.append(k), process(self, k)))
+    with pytest.raises(RuntimeError, match="none of its open rows of session windows "
+                                           "on the device tier"):
+        _run(more=SESSION)
+    assert sent == [0]
