@@ -427,14 +427,18 @@ def apply_overrides(config: dict, traffic: dict, overrides: Optional[dict]) -> N
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              t_start: float, log: Callable[[str], None] = print,
              late_policy: Optional[str] = None,
-             overrides: Optional[dict] = None) -> dict:
+             overrides: Optional[dict] = None,
+             on_trace: Optional[Callable[[Window, float, str], None]] = None) -> dict:
     """Run cell ``name`` once and return its result line as a dict.
 
     ``t_start`` is the ``perf_counter`` reading at the start of the process,
     so that ``setup_s`` covers the imports.  ``late_policy`` replaces the
     configuration's (the control runs the program's ``"drop"`` path), and
     ``overrides`` updates the configuration's keys and groups, and the
-    traffic mix under ``"traffic"`` (tests shrink a cell with it)."""
+    traffic mix under ``"traffic"`` (tests shrink a cell with it).  A traced
+    run hands ``on_trace`` the window, the anchor's ``perf_counter`` reading
+    and the path of the profile, before the profile is deleted (the
+    recorder keeps them)."""
     import jax
 
     bench, cell, config, traffic = load_cell(name)
@@ -543,14 +547,17 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             for m in cell_metrics(bench, name, "end_to_end")
         }
     else:
-        dtrace = devtrace.load(devtrace.find_xplane(TRACE_DIR), anchor)
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        xplane = devtrace.find_xplane(TRACE_DIR)
+        dtrace = devtrace.load(xplane, anchor)
         cells = chunk_cells(items, config)
         win = Window(
             t0=t0, t1=t1, chunks=len(done), cells=cells[warm:warm + len(done)],
             compiles=in_window[0], spans=spans, trace=dtrace,
             peaks=load_peaks(dev.device_kind),
         )
+        if on_trace:
+            on_trace(win, anchor, xplane)
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
         result["metrics"] = {}
         for m in cell_metrics(bench, name, "per_layer"):
             v = load_metric(m["name"])(win)

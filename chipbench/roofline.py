@@ -1,10 +1,12 @@
 """Kernel cost functions, and where the kernels are in the device trace.
 
-The program gives its Pallas kernels no name that reaches the trace: an op
-of one is a ``custom-call`` to :data:`PALLAS_TARGET`.  On the keyed plane's
-path the one Pallas kernel is the batched table lookup, which the table
-dispatches and waits for inside its ``table_update`` span; so its ops are
-the Pallas calls that run inside a ``table_update`` span.
+In the device trace an op of a Pallas kernel is a ``custom-call`` to
+:data:`PALLAS_TARGET`, named for the jitted program that holds it: the
+keyed plane's table lookup is ``%batched_table_lookup.N``.  The match keys
+on the call target, not on that name.  On the keyed plane's path the one
+Pallas kernel is the batched table lookup, which the table dispatches and
+waits for inside its ``table_update`` span; so its ops are the Pallas calls
+that run inside a ``table_update`` span.
 """
 
 from chipbench import devtrace

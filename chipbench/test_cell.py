@@ -13,16 +13,11 @@ import numpy as np
 import pytest
 
 from chipbench import bidstream, harness
+from chipbench.recording import SESSION, SLIDING, TINY
 from repro.keyed import kernels as kk
 from repro.keyed.runtime import KeyedWindowAdapter
 from repro.keyed.table import BatchedWindowTable
 from repro.kernels import ops
-
-#: 2 shards of 256 rows, chunks of 256 bids over 200 ms of event time,
-#: windows of 2 s (sliding by 400 ms), so that windows fire, bids come late
-#: and rows spill to the host tier within a dozen chunks
-TINY = {"plane": {"shards": 2, "num_slots": 8, "chunk": 256}, "capacity": 256,
-        "tps": 1391, "window": {"size_ms": 2000, "slide_ms": 400}}
 
 
 @pytest.fixture
@@ -32,13 +27,6 @@ def interpret():
         yield
     finally:
         ops.use_kernels("auto")
-
-
-#: the same cell with Q5's shape: hopping windows, keyed by auction
-SLIDING = {"key": "auction", "window": {**TINY["window"], "kind": "sliding"}}
-#: the same cell with Q11's shape: session windows keyed by bidder, with a
-#: gap short enough against the jitter that some bids come late
-SESSION = {"window": {"kind": "session", "gap_ms": 40}}
 
 
 def _run(cell="q12_tumble.saturate", more=None, **kw):
@@ -113,35 +101,42 @@ def test_the_command_refuses_a_machine_without_a_tpu():
 
 
 def test_session_plane_checks_exact_and_a_dropped_row_shows(interpret):
-    """The program keeps session windows in its host store; driven directly,
-    a dozen chunks check exact, and one emission or late row left out is
-    counted."""
+    """A session plane driven directly for a dozen chunks checks exact, late
+    bids and open sessions included, and one emission, late row or open
+    session left out is counted."""
     _, _, config, traffic = harness.load_cell("q12_tumble.saturate")
     harness.apply_overrides(config, traffic, {**TINY, **SESSION})
     stream = bidstream.BidStream.for_config(config, 2**31 + 17)
     ex = harness.build_plane(config, late_policy="side")
     chunks = [stream.chunk(k) for k in range(12)]
     outs = [ex.process(c) for c in chunks]
-    rows = harness._rows(ex)
-    assert rows["resident"] == 0 and rows["spill"] > 0
     items, snapshot = np.concatenate(chunks), ex.snapshot_barrier()
     checks = harness.check(outs, snapshot, items, config)
     assert all(c["value"] == 0 and c["limit"] == 0 for c in checks.values())
     assert sum(len(o["late"]["key"]) for o in outs) > 0
+    assert len(snapshot["w_key"]) > 0
     for channel, off in (("emissions", "emission_rows_off"), ("late", "late_rows_off")):
         k = next(i for i, o in enumerate(outs) if len(o[channel]["key"]))
         cut = list(outs)
         cut[k] = {**outs[k], channel: {c: v[1:] for c, v in outs[k][channel].items()}}
         assert harness.check(cut, snapshot, items, config)[off]["value"] > 0
+    short = {k: v[1:] if k.startswith("w_") else v for k, v in snapshot.items()}
+    assert harness.check(outs, short, items, config)["open_rows_off"]["value"] > 0
 
 
 def test_a_plane_with_no_state_on_the_device_stops_after_its_first_chunk(interpret,
                                                                          monkeypatch):
+    """Whatever the window kind and wherever the program keeps its state, a
+    plane that reports open rows and none on the device tier stops the run
+    after chunk 0, naming the configuration, the kind and both counts."""
     sent = []
     process = harness._Source.process
     monkeypatch.setattr(harness._Source, "process",
                         lambda self, k: (sent.append(k), process(self, k)))
-    with pytest.raises(RuntimeError, match="none of its open rows of session windows "
-                                           "on the device tier"):
+    monkeypatch.setattr(harness, "_rows", lambda ex: {"resident": 0, "spill": 335})
+    with pytest.raises(RuntimeError, match=r"configuration nexmark_q12_tumble: .* none "
+                                           r"of its open rows of session windows on the "
+                                           r"device tier \(0 resident rows, 335 in the "
+                                           r"host store\)"):
         _run(more=SESSION)
     assert sent == [0]

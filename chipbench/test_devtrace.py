@@ -1,26 +1,33 @@
-"""The trace reduction, on a small trace recorded on a TPU v5 lite chip."""
+"""The trace reduction, on the trace of ``q12_tumble.saturate`` recorded on
+a TPU v5 lite chip; and every per-layer metric read on the recorded trace
+of each cell that reports it."""
 
-import json
-import os
-
-import numpy as np
 import pytest
 
-from chipbench import devtrace, harness, roofline
-from repro.obs.trace import SpanRecord
+from chipbench import devtrace, harness, recording, roofline
 
-FIX = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
+BENCH = harness.load_benchmark()
 
 
 @pytest.fixture(scope="module")
-def win():
-    with open(os.path.join(FIX, "tiny_q12.json")) as f:
-        d = json.load(f)
-    trace = devtrace.load(os.path.join(FIX, "tiny_q12.xplane.pb"), d["anchor"])
-    spans = [SpanRecord(n, t0, t1, tid, depth) for n, t0, t1, tid, depth in d["spans"]]
-    return harness.Window(t0=d["t0"], t1=d["t1"], chunks=d["chunks"],
-                          cells=np.array(d["cells"]), compiles=0, spans=spans,
-                          trace=trace, peaks=harness.load_peaks("TPU v5 lite"))
+def recorded():
+    """The recorded window of a cell, loaded once; a cell with none fails
+    with the command line that records it."""
+    windows = {}
+
+    def get(cell):
+        if cell not in windows:
+            windows[cell] = recording.load(cell)
+        if windows[cell] is None:
+            pytest.fail(f"no recorded trace of {cell}: record one on a TPU with "
+                        f"{recording.command(cell)}")
+        return windows[cell]
+    return get
+
+
+@pytest.fixture(scope="module")
+def win(recorded):
+    return recorded("q12_tumble.saturate")
 
 
 def test_busy_and_idle_cover_the_window(win):
@@ -30,7 +37,11 @@ def test_busy_and_idle_cover_the_window(win):
     assert sum(b - a for a, b in gaps) + busy == pytest.approx(win.seconds, rel=1e-6)
     labels = devtrace.label_gaps(gaps, win.spans)
     assert sum(labels.values()) == pytest.approx(win.seconds - busy, rel=1e-6)
-    assert "table_update" in labels
+    # the deepest span holding a gap names it: since the table update has
+    # spans below it, its idle time falls under them
+    table = {"table_update", "lookup", "lookup.pack", "lookup.ship", "lookup.dispatch",
+             "lookup.wait", "claim"}
+    assert table & set(labels)
 
 
 def test_ops_are_named_and_summed(win):
@@ -54,9 +65,15 @@ def test_one_lookup_kernel_per_chunk(win):
     assert s == pytest.approx(sum(e[2] - e[1] for e in calls) / 1e9)
 
 
-@pytest.mark.parametrize("name", [m["name"] for m in harness.load_benchmark()["per_layer"]])
-def test_every_reader_reads_the_recorded_trace(win, name):
-    v = harness.load_metric(name)(win)
+def _metric_cells():
+    """Each (per-layer metric, cell that reports it) pair."""
+    return [(m["name"], w["name"]) for w in BENCH["workloads"]
+            for m in harness.cell_metrics(BENCH, w["name"], "per_layer")]
+
+
+@pytest.mark.parametrize("name,cell", _metric_cells())
+def test_every_reader_reads_the_recorded_trace(recorded, name, cell):
+    v = harness.load_metric(name)(recorded(cell))
     assert v is not None and v >= 0
     if name.startswith(("lookup_roofline", "device_idle_pct")):
         assert 0 < v < 100

@@ -69,11 +69,26 @@ def test_reader_reads_its_spans_per_chunk(name):
     assert harness.load_metric(name)(_window(_chunks(2))) == pytest.approx(WANT[name])
 
 
+#: what a reader reads on a traced window without its spans, where that is
+#: not nothing: no program built reads 0
+WITHOUT = {"compile_ms.sat": 0.0}
+
+
 @pytest.mark.parametrize("name", READERS)
 def test_reader_reads_nothing_without_its_spans(name):
     assert harness.load_metric(name)(_window([])) is None
     stages_only = [s for s in _chunks(2) if s.depth <= 1]
-    assert harness.load_metric(name)(_window(stages_only)) is None
+    assert harness.load_metric(name)(_window(stages_only)) == WITHOUT.get(name)
+
+
+def test_compile_ms_reads_zero_where_nothing_is_built_in_the_window():
+    """The jax.* spans of a chunk before the window, and none inside it, read
+    0; with no chunk in the window they read nothing."""
+    built_before = [s for s in _chunks(3) if s.name.startswith("jax.") == (s.t0 < 10.0)]
+    assert harness.load_metric("compile_ms.sat")(
+        _window(built_before, t0=10.0, t1=30.0)) == 0.0
+    assert harness.load_metric("compile_ms.sat")(
+        _window(built_before, t0=10.0, t1=30.0, chunks=0)) is None
 
 
 @pytest.mark.parametrize("name", READERS)
